@@ -59,6 +59,7 @@ def run_multidev(script: str, ndev: int = 8, timeout: int = 600) -> dict:
     line starting with RESULT:."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
+    env["JAX_PLATFORMS"] = "cpu"         # fake devices; never take a chip
     env["PYTHONPATH"] = "src"
     out = subprocess.run([sys.executable, "-c", script], env=env, text=True,
                          capture_output=True, timeout=timeout, cwd=_repo_root())

@@ -1,7 +1,1 @@
-"""WideJAX: MPWide's wide-area communication model reproduced on JAX.
-
-Importing the package installs the JAX compatibility adapters (new-style
-``jax.shard_map`` / ``jax.set_mesh`` API on older jaxlib) before any
-submodule touches them.
-"""
-from repro import compat  # noqa: F401  (side effect: compat.install())
+"""WideJAX: MPWide's wide-area communication model reproduced on JAX."""

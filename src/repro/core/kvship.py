@@ -23,6 +23,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -150,16 +151,22 @@ def _encode_decode(arr: np.ndarray, compress: str) -> tuple:
         out = np.asarray(jnp.asarray(arr).astype(jnp.bfloat16)
                          .astype(arr.dtype))
         return out.reshape(arr.shape), 2 * arr.size
-    from repro.kernels import ops
     flat = jnp.asarray(arr).astype(jnp.float32).reshape(-1)
     pad = (-flat.shape[0]) % QBLOCK
     if pad:
         flat = jnp.pad(flat, (0, pad))
-    q, s = ops.quant_int8(flat, block=QBLOCK)
-    wire = int(np.asarray(q).nbytes + np.asarray(s).nbytes)
-    y = ops.dequant_int8(q, s, block=QBLOCK, dtype=jnp.float32)
+    q, s, y = _int8_roundtrip(flat)
     y = y[:arr.size].reshape(arr.shape).astype(arr.dtype)
-    return np.asarray(y), wire
+    return np.asarray(y), int(q.nbytes + s.nbytes)
+
+
+@jax.jit
+def _int8_roundtrip(flat: jax.Array) -> tuple:
+    """The int8 codec on one flat chunk: (codes, scales, decoded); one
+    compiled program per chunk length."""
+    from repro.kernels import ops
+    q, s = ops.quant_int8(flat, block=QBLOCK)
+    return q, s, ops.dequant_int8(q, s, block=QBLOCK, dtype=jnp.float32)
 
 
 def _corrupts(health, rid: int, hop: int, attempt: int) -> bool:

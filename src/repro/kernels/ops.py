@@ -1,10 +1,12 @@
 """Jit-friendly dispatch wrappers around the Pallas kernels.
 
-On TPU the Pallas path runs; everywhere else (this container is CPU-only) a
-memory-efficient pure-jnp implementation lowers instead, so the dry-run HLO
-has bounded working sets (the kv-block-scan below is the jnp mirror of the
-flash kernel's online softmax).  `impl=` overrides for tests:
-"pallas_interpret" executes the actual kernel body in Python on CPU.
+On TPU the Pallas path runs; on other backends a memory-efficient pure-jnp
+implementation lowers instead, so the CPU dry-run HLO has bounded working
+sets (the kv-block-scan below is the jnp mirror of the flash kernel's
+online softmax).  `impl=` overrides for tests: "pallas_interpret" executes
+the actual kernel body in Python on CPU.  Kernel calls run per shard
+(`sharding.per_shard`), since GSPMD cannot partition a Mosaic kernel; flash
+attention and rmsnorm are differentiable through their custom VJPs.
 """
 from __future__ import annotations
 
@@ -19,14 +21,17 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import quant as _q
 from repro.kernels import ref as _ref
 from repro.kernels import rmsnorm as _rn
-from repro.sharding import TP_AXIS, constrain
+from repro.sharding import TP_AXIS, axis_size, constrain, per_shard
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
+
+
+def _tp_if_divides(n: int) -> Optional[str]:
+    """TP_AXIS when a kernel's independent dim of extent `n` splits evenly
+    over it (the kernel then runs on its shard), else None (replicated)."""
+    return TP_AXIS if n % axis_size(TP_AXIS) == 0 else None
 
 
 def attn_shard_mode(B: int, KH: int = 0) -> Optional[str]:
@@ -47,7 +52,6 @@ def attn_shard_mode(B: int, KH: int = 0) -> Optional[str]:
     """
     if os.environ.get("REPRO_ATTN_SP", "1") != "1":
         return None
-    from repro.sharding import axis_size
     tp = axis_size(TP_AXIS)
     if tp <= 1:
         return None
@@ -209,10 +213,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         q3 = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
         k3 = k.transpose(0, 2, 1, 3).reshape(B * KH, Sk, D)
         v3 = v.transpose(0, 2, 1, 3).reshape(B * KH, Sk, D)
-        o3 = _fa.flash_attention_bhsd(
-            q3, k3, v3, group=g, causal=causal, window=window, scale=scale,
-            block_q=block_q, block_k=block_k,
+        kern = functools.partial(
+            _fa.flash_attention_bhsd, group=g, causal=causal, window=window,
+            scale=scale, block_q=block_q, block_k=block_k,
             interpret=(impl == "pallas_interpret"))
+        # heads over TP when whole kv heads (and their q groups) split evenly
+        hd = (_tp_if_divides(B * KH), None, None)
+        o3 = per_shard(kern, (hd, hd, hd), hd)(q3, k3, v3)
         return o3.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
     raise ValueError(f"unknown impl {impl!r}")
 
@@ -231,8 +238,10 @@ def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-5,
     lead = x.shape[:-1]
     d = x.shape[-1]
     x2 = x.reshape(-1, d)
-    y = _rn.rmsnorm_rows(x2, w, eps=eps, interpret=(impl == "pallas_interpret"))
-    return y.reshape(*lead, d)
+    kern = functools.partial(_rn.rmsnorm_rows, eps=eps,
+                             interpret=(impl == "pallas_interpret"))
+    rows = (_tp_if_divides(x2.shape[0]), None)
+    return per_shard(kern, (rows, (None,)), rows)(x2, w).reshape(*lead, d)
 
 
 # ---------------------------------------------------------------------------
@@ -259,9 +268,13 @@ def quant_int8(x: jax.Array, *, block: int = 256, impl: str = "auto"):
         return _ref.quant_int8_ref(x, block)
     lead = x.shape[:-1]
     n = x.shape[-1]
-    q, s = _q.quant_int8_2d(x.reshape(-1, n), block=block,
-                            interpret=(impl == "pallas_interpret"))
-    return q.reshape(*lead, n), s.reshape(*lead, n // block)
+    # kernel layout (block, M): one quantization block per lane column
+    xt = x.reshape(-1, block).T
+    cols = (None, _tp_if_divides(xt.shape[1]))
+    kern = functools.partial(_q.quant_int8_cols,
+                             interpret=(impl == "pallas_interpret"))
+    q, s = per_shard(kern, (cols,), (cols, cols))(xt)
+    return q.T.reshape(*lead, n), s.reshape(*lead, n // block)
 
 
 def dequant_int8(q: jax.Array, s: jax.Array, *, block: int = 256,
@@ -272,7 +285,9 @@ def dequant_int8(q: jax.Array, s: jax.Array, *, block: int = 256,
         return _ref.dequant_int8_ref(q, s, block, dtype)
     lead = q.shape[:-1]
     n = q.shape[-1]
-    x = _q.dequant_int8_2d(q.reshape(-1, n), s.reshape(-1, n // block),
-                           block=block, dtype=dtype,
-                           interpret=(impl == "pallas_interpret"))
-    return x.reshape(*lead, n)
+    qt = q.reshape(-1, block).T
+    cols = (None, _tp_if_divides(qt.shape[1]))
+    kern = functools.partial(_q.dequant_int8_cols, dtype=dtype,
+                             interpret=(impl == "pallas_interpret"))
+    x = per_shard(kern, (cols, cols), cols)(qt, s.reshape(1, -1))
+    return x.T.reshape(*lead, n)

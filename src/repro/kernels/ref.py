@@ -63,7 +63,10 @@ def quant_int8_ref(x: jax.Array, block: int = 256):
                          f"of block {block}")
     xb = x.astype(jnp.float32).reshape(*lead, n // block, block)
     amax = jnp.max(jnp.abs(xb), axis=-1, keepdims=True)
-    scale = jnp.where(amax > 0, amax / 127.0, 1.0)
+    # amax * (1/127), not amax / 127: XLA rewrites a division by a constant
+    # into this product under jit, so the product keeps eager, jitted and
+    # kernel evaluations bit-identical
+    scale = jnp.where(amax > 0, amax * (1.0 / 127.0), 1.0)
     q = jnp.clip(jnp.round(xb / scale), -127, 127).astype(jnp.int8)
     return q.reshape(*lead, n), scale.squeeze(-1)
 

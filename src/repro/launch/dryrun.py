@@ -1,6 +1,7 @@
 import os
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS", ""))
+os.environ["JAX_PLATFORMS"] = "cpu"     # fake devices; never take a chip
 # ^ MUST precede any jax import: jax locks the device count on first init.
 # (This also means: no `from __future__ import annotations` in this module.)
 

@@ -16,6 +16,7 @@ import numpy as np
 from repro.configs import (SHAPES, CommConfig, RunConfig, ShapeConfig,
                            TrainConfig, get_config, smoke_config)
 from repro.core.path import WAN_LONDON_POZNAN, WidePath
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.runtime import Server, ServingEngine
 
@@ -105,6 +106,7 @@ def main():
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if cfg.num_heads == 0 and cfg.family == "audio":
         raise SystemExit("decode not defined for this arch")

@@ -22,6 +22,7 @@ import jax
 from repro.configs import (SHAPES, CommConfig, RunConfig, ShapeConfig,
                            TrainConfig, get_config, smoke_config)
 from repro.data import DataConfig, make_pipeline
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.runtime import Trainer
 
@@ -79,6 +80,7 @@ def main():
     ap.add_argument("--data-path", default=None)
     args = ap.parse_args()
 
+    enable_compile_cache()
     maybe_init_distributed()
     cfg = get_config(args.arch)
     if args.smoke:

@@ -8,9 +8,10 @@ helpers are no-ops, so the same model code runs in single-device smoke tests.
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Sequence, Union
+from typing import Optional, Sequence, Union
 
 import jax
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
 DP_AXES: tuple[str, ...] = ("pod", "data")   # data-parallel axes (outer first)
@@ -21,23 +22,9 @@ AxisEntry = Union[None, str, Sequence[str]]
 
 def _auto_axes() -> set[str]:
     """Mesh axes GSPMD may shard over (present and not shard_map-manual)."""
-    try:
-        m = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return set()
-    if m is None:
-        return set()
-    names = getattr(m, "axis_names", ()) or ()
-    if not names:
-        return set()
-    types = getattr(m, "axis_types", None)
-    out = set()
-    for i, n in enumerate(names):
-        t = types[i] if types is not None and i < len(types) else None
-        if t is not None and "Manual" in str(t):
-            continue
-        out.add(n)
-    return out
+    m = jax.sharding.get_abstract_mesh()
+    return {n for n, t in zip(m.axis_names, m.axis_types)
+            if t != AxisType.Manual}
 
 
 def filter_spec(*entries: AxisEntry) -> Optional[P]:
@@ -72,7 +59,7 @@ def filter_spec(*entries: AxisEntry) -> Optional[P]:
 
 
 def constrain(x: jax.Array, *entries: AxisEntry) -> jax.Array:
-    """`with_sharding_constraint` that degrades gracefully.
+    """`with_sharding_constraint` over the axes that exist right now.
 
     ``constrain(x, DP_AXES, None, TP_AXIS)`` shards dim0 over ("pod","data")
     and dim2 over "model" — on whatever subset of those axes exists and is
@@ -81,38 +68,46 @@ def constrain(x: jax.Array, *entries: AxisEntry) -> jax.Array:
     spec = filter_spec(*entries)
     if spec is None:
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, spec)
+
+
+def per_shard(fn, in_specs: tuple, out_specs):
+    """`fn` made manual over every mesh axis GSPMD could still partition.
+
+    For Pallas kernel calls: Mosaic kernels cannot be auto-partitioned, so
+    inside a shard_map that leaves an axis auto (the train step is manual
+    over the DP axes only) or under a multi-device mesh, the kernel runs
+    per shard.  Specs are tuples of axis intents as for :func:`constrain`,
+    one per argument (a tuple of them for several outputs); outside any
+    mesh `fn` comes back unchanged.
+    """
+    axes = _auto_axes()
+    if not axes:
+        return fn
+
+    def spec(entries):
+        return filter_spec(*entries) or P()
+
+    outs = (tuple(spec(e) for e in out_specs)
+            if isinstance(out_specs[0], tuple) else spec(out_specs))
+    # every axis, the enclosing shard_map's manual ones too: a nested
+    # shard_map over the auto axes alone lowers with only those manual,
+    # which Mosaic refuses
+    names = set(jax.sharding.get_abstract_mesh().axis_names)
+    return jax.shard_map(fn, in_specs=tuple(spec(e) for e in in_specs),
+                         out_specs=outs, axis_names=names, check_vma=False)
 
 
 def manual_axes_present(*names: str) -> tuple[str, ...]:
     """Which of `names` are *manual* axes right now (i.e. usable by explicit
     collectives like psum/ppermute). Inside shard_map, only the axes in
     `axis_names` qualify; auto axes would raise 'unbound axis name'."""
-    try:
-        m = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return ()
-    axis_names = getattr(m, "axis_names", ()) or ()
-    types = getattr(m, "axis_types", None)
-    out = []
-    for i, n in enumerate(axis_names):
-        if n not in names:
-            continue
-        t = types[i] if types is not None and i < len(types) else None
-        if t is not None and "Manual" in str(t):
-            out.append(n)
-    return tuple(n for n in names if n in out)
+    m = jax.sharding.get_abstract_mesh()
+    manual = {n for n, t in zip(m.axis_names, m.axis_types)
+              if t == AxisType.Manual}
+    return tuple(n for n in names if n in manual)
 
 
 def axis_size(name: str) -> int:
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        names = list(getattr(m, "axis_names", ()) or ())
-        if name in names:
-            return int(m.shape[name])
-    except Exception:
-        pass
-    return 1
+    m = jax.sharding.get_abstract_mesh()
+    return int(m.shape[name]) if name in m.axis_names else 1

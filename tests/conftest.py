@@ -116,6 +116,7 @@ def multidev():
     def run(script: str, ndev: int = 8, timeout: int = 900) -> dict:
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
+        env["JAX_PLATFORMS"] = "cpu"     # fake devices; never take a chip
         env["PYTHONPATH"] = os.path.join(REPO, "src")
         out = subprocess.run([sys.executable, "-c", script], env=env,
                              text=True, capture_output=True, timeout=timeout,

@@ -89,6 +89,7 @@ def test_python_block_compiles(block):
 def test_python_block_executes(block):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"         # fake devices; never take a chip
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run([sys.executable, "-c", block.code], env=env,
                          text=True, capture_output=True, timeout=600,

@@ -184,10 +184,11 @@ def test_quant_int8_ref_block_mismatch_names_shapes():
 
 
 def test_quant_int8_2d_block_mismatch_names_shapes():
-    from repro.kernels.quant import quant_int8_2d
+    # the kernel path refuses a ragged trailing dim; it never falls back
+    from repro.kernels.ops import quant_int8
     import jax.numpy as jnp
-    with pytest.raises(ValueError, match=r"last dim 10.*block 256"):
-        quant_int8_2d(jnp.zeros((4, 10)))
+    with pytest.raises(ValueError, match=r"trailing dim 10.*block=256"):
+        quant_int8(jnp.zeros((4, 10)), impl="pallas_interpret")
 
 
 def test_flash_attention_gqa_mismatch_names_heads():

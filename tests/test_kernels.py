@@ -135,3 +135,72 @@ def test_quant_zero_block():
     q, s = ops.quant_int8(x, impl="pallas_interpret")
     y = ops.dequant_int8(q, s, impl="pallas_interpret")
     assert np.asarray(y).sum() == 0 and np.isfinite(np.asarray(s)).all()
+
+
+# ---------------------------------------------------------------------------
+# gradients: kernel forward, jnp backward (custom_vjp)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("B,Sq,Sk,H,KH,D,causal,window", [
+    (2, 64, 64, 4, 2, 16, True, None),     # GQA
+    (1, 37, 37, 4, 4, 32, True, 24),       # ragged blocks, sliding window
+    (1, 40, 40, 6, 3, 16, False, None),    # non-causal
+    (2, 1, 77, 4, 2, 32, True, None),      # decode-style suffix
+])
+def test_flash_attention_grad_matches_ref(B, Sq, Sk, H, KH, D, causal,
+                                          window):
+    q = _rand(16, (B, Sq, H, D), jnp.float32)
+    k = _rand(17, (B, Sk, KH, D), jnp.float32)
+    v = _rand(18, (B, Sk, KH, D), jnp.float32)
+    w = _rand(19, (B, Sq, H, D), jnp.float32)
+
+    def loss(impl):
+        def f(q, k, v):
+            o = ops.flash_attention(q, k, v, causal=causal, window=window,
+                                    impl=impl, block_q=32, block_k=32)
+            return jnp.sum(o * w)
+        return jax.grad(f, argnums=(0, 1, 2))
+
+    got = loss("pallas_interpret")(q, k, v)
+    want = loss("ref")(q, k, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("R,d,dtype", [(70, 384, jnp.float32),
+                                       (33, 128, jnp.bfloat16)])
+def test_rmsnorm_grad_matches_ref(R, d, dtype):
+    x = _rand(20, (R, d), dtype)
+    w = _rand(21, (d,), jnp.float32)
+    g = _rand(22, (R, d), jnp.float32)
+
+    def grads(impl):
+        return jax.grad(lambda x, w: jnp.sum(
+            ops.rmsnorm(x, w, impl=impl).astype(jnp.float32) * g),
+            argnums=(0, 1))(x, w)
+
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
+    for a, b in zip(grads("pallas_interpret"), grads("ref")):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=tol, rtol=tol)
+
+
+# (payload shape, block) of the codec's callers, at CPU-sized extents
+@pytest.mark.parametrize("shape,block", [
+    ((24 * 37 * 16 * 64,), 256),   # kvship: a KV chunk flattened to 1-D
+    ((5, 2, 512), 256),            # compress.quant_chunk: leaf, dim last
+    ((1500, 3), 3),                # ring: one 3-value block per row
+    ((64, 40, 12), 12),            # ring: a 12-row segment
+    ((40, 1), 1),                  # ring: single-value blocks
+])
+def test_quant_kernel_bit_identical_to_ref(shape, block):
+    x = _rand(23, shape, jnp.float32) * 3
+    qk, sk = ops.quant_int8(x, block=block, impl="pallas_interpret")
+    qr, sr = ref.quant_int8_ref(x, block)
+    np.testing.assert_array_equal(np.asarray(qk), np.asarray(qr))
+    np.testing.assert_array_equal(np.asarray(sk), np.asarray(sr))
+    yk = ops.dequant_int8(qk, sk, block=block, impl="pallas_interpret")
+    yr = ref.dequant_int8_ref(qr, sr, block)
+    np.testing.assert_array_equal(np.asarray(yk), np.asarray(yr))

@@ -462,7 +462,7 @@ def test_src_is_clean_ast_rules():
 
 
 def test_cli_end_to_end_json_exit_codes(tmp_path):
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-m", "tools.mpwlint", "src", "--format=json",
          "--no-semantic"],
@@ -487,6 +487,7 @@ def test_cli_end_to_end_json_exit_codes(tmp_path):
 def test_cli_full_run_including_semantic():
     out = subprocess.run(
         [sys.executable, "-m", "tools.mpwlint", "src"],
-        cwd=REPO, capture_output=True, text=True)
+        cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True)
     assert out.returncode == 0, out.stdout + out.stderr
     assert "0 finding(s)" in out.stdout
