@@ -145,19 +145,21 @@ def _encode_decode(arr: np.ndarray, compress: str) -> tuple:
     ``none`` is byte-identical; ``bf16``/``int8`` round-trip through the
     wire dtype (int8 flattens to 1-D and pads to the quantization block, so
     padding waste never exceeds QBLOCK-1 elements per chunk)."""
-    if compress == "none":
-        return arr, arr.nbytes
-    if compress == "bf16":
-        out = np.asarray(jnp.asarray(arr).astype(jnp.bfloat16)
-                         .astype(arr.dtype))
-        return out.reshape(arr.shape), 2 * arr.size
-    flat = jnp.asarray(arr).astype(jnp.float32).reshape(-1)
-    pad = (-flat.shape[0]) % QBLOCK
-    if pad:
-        flat = jnp.pad(flat, (0, pad))
-    q, s, y = _int8_roundtrip(flat)
-    y = y[:arr.size].reshape(arr.shape).astype(arr.dtype)
-    return np.asarray(y), int(q.nbytes + s.nbytes)
+    with tel.span("kvship.codec", bytes=arr.nbytes,
+                  wire=_encoded_nbytes(arr.size, arr.itemsize, compress)):
+        if compress == "none":
+            return arr, arr.nbytes
+        if compress == "bf16":
+            out = np.asarray(jnp.asarray(arr).astype(jnp.bfloat16)
+                             .astype(arr.dtype))
+            return out.reshape(arr.shape), 2 * arr.size
+        flat = jnp.asarray(arr).astype(jnp.float32).reshape(-1)
+        pad = (-flat.shape[0]) % QBLOCK
+        if pad:
+            flat = jnp.pad(flat, (0, pad))
+        q, s, y = _int8_roundtrip(flat)
+        y = y[:arr.size].reshape(arr.shape).astype(arr.dtype)
+        return np.asarray(y), int(q.nbytes + s.nbytes)
 
 
 @jax.jit
@@ -203,134 +205,143 @@ def ship_kv(kv: dict, plan: KVShipPlan, rid: int, *,
     surviving links (``reroute``).  With no route left, :class:`ShipError`
     is raised — the batcher's cue to degrade to collocated serving.
     """
-    path = plan.path
-    if max_reships < 0:
-        raise ValueError(f"max_reships must be >= 0, got {max_reships}")
-    if route is not None and len(route.profiles) != path.n_hops:
-        raise ValueError(f"route has {len(route.profiles)} hops but the "
-                         f"plan's path has {path.n_hops} — re-plan after "
-                         f"a topology change")
-    arrs = []
-    for name, shape in zip(plan.leaf_names, plan.shapes):
-        if name not in kv:
-            raise ValueError(f"kv is missing leaf {name!r} the plan was "
-                             f"built for (have {sorted(kv)})")
-        a = np.asarray(kv[name])
-        if tuple(a.shape) != shape:
-            raise ValueError(f"kv leaf {name!r} has shape {tuple(a.shape)} "
-                             f"but the plan was frozen for {shape} — "
-                             f"re-plan on cache-geometry change")
-        arrs.append(a)
-    key = f"serve/req{rid}/kv"
-    tel.note_plan(key, payload_bytes=plan.payload_bytes,
-                  n_chunks=len(plan.chunks),
-                  streams_used=plan.streams_used,
-                  streams_configured=path.streams,
-                  chunk_bytes=path.chunk_bytes, pacing=path.comm.pacing,
-                  load_balance=plan.load_balance, algo="shift",
-                  wire_bytes=plan.wire_bytes_hop)
-    pol = KVSHIP_RETRY if retry is None else retry
-    hops = list(path.route)
-    profs = list(route.profiles) if route is not None else [None] * len(hops)
-    sites = list(route.sites) if route is not None else []
-    avoid: set = set()
-    per_hop_s = []
-    total_s = 0.0
-    reships = reroutes = faults = 0
-    i = 0
-    while i < len(hops):
-        hop = hops[i]
-        prof = profs[i]
-        # fault gate: a dead hop or a corrupted attempt burns the watchdog
-        # and retries; exhausted retries replan the remaining hops
-        attempt = 0
-        while prof is not None and step is not None:
-            if faults > _MAX_SHIP_FAULTS:
-                raise ShipError(f"req{rid}: ship exceeded {_MAX_SHIP_FAULTS} "
-                                f"fault responses at hop {i} ({hop.name})")
-            health = prof.health(int(step) + attempt)
-            corrupt = health.alive and _corrupts(health, rid, i, attempt)
-            if health.alive and not corrupt:
-                break
-            faults += 1
-            total_s += float(timeout_s)
-            if corrupt:
-                tel.note_checksum_error(f"{key}/hop{i}:{hop.name}")
-            if attempt < max_reships:
-                backoff = pol.delay_s(attempt, key=rid * 31 + i)
-                total_s += backoff
-                reships += 1
-                attempt += 1
-                if log is not None:
-                    log.add(int(step) + attempt, "reship", hop.name,
-                            {"rid": rid,
-                             "reason": "corrupt" if corrupt else "dead",
-                             "attempt": attempt,
-                             "backoff_s": round(backoff, 6)})
-                continue
-            # reships exhausted: replan from the stranded site
-            if topo is None:
-                raise ShipError(
-                    f"req{rid}: hop {i} ({hop.name}) still faulty after "
-                    f"{max_reships} reship(s) and no topology to replan on")
-            avoid.add((sites[i], sites[i + 1]))
-            avoid.add((sites[i + 1], sites[i]))
-            try:
-                nr = topo.route(sites[i], sites[-1],
-                                avoid=frozenset(avoid))
-            except (KeyError, ValueError):
-                raise ShipError(
-                    f"req{rid}: no surviving route {sites[i]} -> "
-                    f"{sites[-1]} after {reships} reship(s)")
-            reroutes += 1
-            if log is not None:
-                log.add(int(step) + attempt, "reroute", hop.name,
-                        {"rid": rid, "route": list(nr.sites)})
-            hops = hops[:i] + list(nr.as_hops(base_comm=path.comm))
-            profs = profs[:i] + list(nr.profiles)
-            sites = sites[:i] + list(nr.sites)
+    with tel.span("kvship.ship", rid=rid, chunks=len(plan.chunks),
+                  hops=plan.n_hops):
+        path = plan.path
+        if max_reships < 0:
+            raise ValueError(f"max_reships must be >= 0, got {max_reships}")
+        if route is not None and len(route.profiles) != path.n_hops:
+            raise ValueError(f"route has {len(route.profiles)} hops but "
+                             f"the plan's path has {path.n_hops} — re-plan "
+                             f"after a topology change")
+        arrs = []
+        for name, shape in zip(plan.leaf_names, plan.shapes):
+            if name not in kv:
+                raise ValueError(f"kv is missing leaf {name!r} the plan was "
+                                 f"built for (have {sorted(kv)})")
+            a = np.asarray(kv[name])
+            if tuple(a.shape) != shape:
+                raise ValueError(f"kv leaf {name!r} has shape "
+                                 f"{tuple(a.shape)} but the plan was frozen "
+                                 f"for {shape} — re-plan on cache-geometry "
+                                 f"change")
+            arrs.append(a)
+        key = f"serve/req{rid}/kv"
+        tel.note_plan(key, payload_bytes=plan.payload_bytes,
+                      n_chunks=len(plan.chunks),
+                      streams_used=plan.streams_used,
+                      streams_configured=path.streams,
+                      chunk_bytes=path.chunk_bytes, pacing=path.comm.pacing,
+                      load_balance=plan.load_balance, algo="shift",
+                      wire_bytes=plan.wire_bytes_hop)
+        pol = KVSHIP_RETRY if retry is None else retry
+        hops = list(path.route)
+        profs = (list(route.profiles) if route is not None
+                 else [None] * len(hops))
+        sites = list(route.sites) if route is not None else []
+        avoid: set = set()
+        per_hop_s = []
+        total_s = 0.0
+        reships = reroutes = faults = 0
+        i = 0
+        while i < len(hops):
             hop = hops[i]
             prof = profs[i]
+            # fault gate: a dead hop or a corrupted attempt burns the watchdog
+            # and retries; exhausted retries replan the remaining hops
             attempt = 0
-        hop_bytes = 0
-        out = [None] * len(arrs)
-        for c in plan.chunks:
-            piece = arrs[c.leaf][c.start:c.start + c.size]
-            decoded, wire = _encode_decode(piece, hop.comm.compress)
-            hop_bytes += wire
-            if out[c.leaf] is None:
-                out[c.leaf] = []
-            out[c.leaf].append((c.start, decoded))
-        if hop_bytes != plan.wire_bytes_hop and hop.comm.compress == path.comm.compress:
-            raise RuntimeError(
-                f"hop {i} encoded {hop_bytes} wire bytes but the plan "
-                f"promised {plan.wire_bytes_hop} — plan and codec disagree")
-        arrs = [np.concatenate([p for _, p in sorted(pieces, key=lambda t: t[0])],
-                               axis=0)
-                for pieces in out]
-        if prof is not None and step is not None:
-            hop_s = simulate_hop_s(
-                hop_bytes, prof, int(step) + attempt, streams=hop.streams,
-                chunk_bytes=hop.chunk_bytes, pacing=hop.comm.pacing,
-                timeout_s=timeout_s)
-        else:
-            hop_s = simulate_transfer_s(
-                hop_bytes, hop.link, streams=hop.streams,
-                chunk_bytes=hop.chunk_bytes, pacing=hop.comm.pacing)
-        per_hop_s.append(hop_s)
-        total_s += hop_s
-        tel.record(f"{key}/hop{i}:{hop.name}", hop_s, nbytes=hop_bytes,
+            while prof is not None and step is not None:
+                if faults > _MAX_SHIP_FAULTS:
+                    raise ShipError(f"req{rid}: ship exceeded "
+                                    f"{_MAX_SHIP_FAULTS} fault responses at "
+                                    f"hop {i} ({hop.name})")
+                health = prof.health(int(step) + attempt)
+                corrupt = health.alive and _corrupts(health, rid, i, attempt)
+                if health.alive and not corrupt:
+                    break
+                faults += 1
+                total_s += float(timeout_s)
+                if corrupt:
+                    tel.note_checksum_error(f"{key}/hop{i}:{hop.name}")
+                if attempt < max_reships:
+                    backoff = pol.delay_s(attempt, key=rid * 31 + i)
+                    total_s += backoff
+                    reships += 1
+                    attempt += 1
+                    if log is not None:
+                        log.add(int(step) + attempt, "reship", hop.name,
+                                {"rid": rid,
+                                 "reason": "corrupt" if corrupt else "dead",
+                                 "attempt": attempt,
+                                 "backoff_s": round(backoff, 6)})
+                    continue
+                # reships exhausted: replan from the stranded site
+                if topo is None:
+                    raise ShipError(
+                        f"req{rid}: hop {i} ({hop.name}) still faulty after "
+                        f"{max_reships} reship(s) and no topology to "
+                        f"replan on")
+                avoid.add((sites[i], sites[i + 1]))
+                avoid.add((sites[i + 1], sites[i]))
+                try:
+                    nr = topo.route(sites[i], sites[-1],
+                                    avoid=frozenset(avoid))
+                except (KeyError, ValueError):
+                    raise ShipError(
+                        f"req{rid}: no surviving route {sites[i]} -> "
+                        f"{sites[-1]} after {reships} reship(s)")
+                reroutes += 1
+                if log is not None:
+                    log.add(int(step) + attempt, "reroute", hop.name,
+                            {"rid": rid, "route": list(nr.sites)})
+                hops = hops[:i] + list(nr.as_hops(base_comm=path.comm))
+                profs = profs[:i] + list(nr.profiles)
+                sites = sites[:i] + list(nr.sites)
+                hop = hops[i]
+                prof = profs[i]
+                attempt = 0
+            hop_bytes = 0
+            out = [None] * len(arrs)
+            for c in plan.chunks:
+                piece = arrs[c.leaf][c.start:c.start + c.size]
+                decoded, wire = _encode_decode(piece, hop.comm.compress)
+                hop_bytes += wire
+                if out[c.leaf] is None:
+                    out[c.leaf] = []
+                out[c.leaf].append((c.start, decoded))
+            if (hop_bytes != plan.wire_bytes_hop
+                    and hop.comm.compress == path.comm.compress):
+                raise RuntimeError(
+                    f"hop {i} encoded {hop_bytes} wire bytes but the plan "
+                    f"promised {plan.wire_bytes_hop} — plan and codec "
+                    f"disagree")
+            arrs = [np.concatenate(
+                        [p for _, p in sorted(pieces, key=lambda t: t[0])],
+                        axis=0)
+                    for pieces in out]
+            if prof is not None and step is not None:
+                hop_s = simulate_hop_s(
+                    hop_bytes, prof, int(step) + attempt, streams=hop.streams,
+                    chunk_bytes=hop.chunk_bytes, pacing=hop.comm.pacing,
+                    timeout_s=timeout_s)
+            else:
+                hop_s = simulate_transfer_s(
+                    hop_bytes, hop.link, streams=hop.streams,
+                    chunk_bytes=hop.chunk_bytes, pacing=hop.comm.pacing)
+            per_hop_s.append(hop_s)
+            total_s += hop_s
+            tel.record(f"{key}/hop{i}:{hop.name}", hop_s, nbytes=hop_bytes,
+                       step=step)
+            i += 1
+        n_hops = len(per_hop_s)
+        tel.record(key, total_s, nbytes=plan.wire_bytes_hop * n_hops,
                    step=step)
-        i += 1
-    n_hops = len(per_hop_s)
-    tel.record(key, total_s, nbytes=plan.wire_bytes_hop * n_hops,
-               step=step)
-    if reships or reroutes:
-        tel.note_ship_retry(key, reships=reships, reroutes=reroutes)
-    return (
-        {n: a for n, a in zip(plan.leaf_names, arrs)},
-        KVShipResult(rid=rid, wire_bytes_hop=plan.wire_bytes_hop,
-                     wire_bytes_total=plan.wire_bytes_hop * n_hops,
-                     modeled_s=total_s, per_hop_s=tuple(per_hop_s),
-                     n_chunks=len(plan.chunks), reships=reships,
-                     reroutes=reroutes, route=tuple(sites)))
+        if reships or reroutes:
+            tel.note_ship_retry(key, reships=reships, reroutes=reroutes)
+        return (
+            {n: a for n, a in zip(plan.leaf_names, arrs)},
+            KVShipResult(rid=rid, wire_bytes_hop=plan.wire_bytes_hop,
+                         wire_bytes_total=plan.wire_bytes_hop * n_hops,
+                         modeled_s=total_s, per_hop_s=tuple(per_hop_s),
+                         n_chunks=len(plan.chunks), reships=reships,
+                         reroutes=reroutes, route=tuple(sites)))

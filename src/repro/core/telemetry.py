@@ -19,6 +19,14 @@ module is that feedback channel for WideJAX: every :class:`WidePath` gets a
 
 The registry is what `MPW.PathStats` / `MPW.Report` read, and what the
 :class:`~repro.core.autotune.OnlineTuner` consumes as its cost signal.
+
+:func:`span` names a stretch of host code for the profiler: with
+``jax.profiler.trace`` running it lands in the same ``.xplane.pb`` as the
+device planes, on the same clock, so a device gap can be put down to what
+the host was doing in it.  A running profiler is the only switch; with none
+a span costs about a microsecond and records nothing.  A span reads no
+clock itself (the profiler stamps it), and its stats are ints the host
+already holds: a device value would add a sync.
 """
 from __future__ import annotations
 
@@ -28,6 +36,8 @@ from collections import deque
 from contextlib import contextmanager
 from dataclasses import asdict, dataclass, field
 from typing import Any, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclass(frozen=True)
@@ -292,3 +302,10 @@ def note_checksum_error(key: str, n: int = 1) -> None:
 
 def note_ship_retry(key: str, reships: int = 0, reroutes: int = 0) -> None:
     _GLOBAL.path(key).note_ship_retry(reships=reships, reroutes=reroutes)
+
+
+def span(name: str, **stats: int):
+    """A host span for the profiler's trace: ``with span("serve.admit",
+    rid=rid, tokens=n): ...``.  Nested spans on one thread nest in the
+    trace.  Pass only ints the host already holds."""
+    return TraceAnnotation(name, **stats)

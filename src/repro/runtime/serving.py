@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import RunConfig
+from repro.core import telemetry as tel
 from repro.core.kvship import KVShipPlan, ShipError, plan_kv_ship, ship_kv
 from repro.core.path import WidePath
 from repro.core.serving import ContinuousBatcher
@@ -102,8 +103,12 @@ class ServingEngine:
         self.results: dict[int, np.ndarray] = {}   # rid -> generated tokens
         self._n_events = 0
         self._ship_plans: dict[tuple, KVShipPlan] = {}
-        self._prefill_fn = jax.jit(
-            lambda p, toks: self.model.prefill(p, {"tokens": toks}))
+
+        def serve_prefill(p, toks):
+            return self.model.prefill(p, {"tokens": toks})
+
+        # a named function, so its program runs as `jit_serve_prefill`
+        self._prefill_fn = jax.jit(serve_prefill)
 
     # -- request intake -----------------------------------------------------
     def submit(self, prompt_tokens: np.ndarray, max_new: int,
@@ -126,24 +131,25 @@ class ServingEngine:
     def step(self) -> int:
         """One engine step: batcher transition + the real work it implies."""
         pre = dict(self._decoding)   # slots decoding before this step
-        self.batcher.step_once()
-        tl = self.batcher.timeline()
-        events = tl[self._n_events:]
-        self._n_events = len(tl)
-        if pre:
-            self._decode_tick(pre)   # batcher rule (3): pre-existing slots
-        for kind, tag, _step in events:
-            rid = int(tag[3:])
-            if kind == "decode":
-                self._on_decode_start(rid)
-            elif kind == "complete":
-                self._on_complete(rid)
-            elif kind == "timeout":
-                self._on_abort(rid, keep_prompt=False)
-            elif kind == "requeue":
-                self._on_abort(rid, keep_prompt=True)
-            elif kind in ("shed", "reject"):
-                self._prompts.pop(rid, None)
+        with tel.span("serve.step", decoding=len(pre)):
+            self.batcher.step_once()
+            tl = self.batcher.timeline()
+            events = tl[self._n_events:]
+            self._n_events = len(tl)
+            if pre:
+                self._decode_tick(pre)   # batcher rule (3): pre-existing slots
+            for kind, tag, _step in events:
+                rid = int(tag[3:])
+                if kind == "decode":
+                    self._on_decode_start(rid)
+                elif kind == "complete":
+                    self._on_complete(rid)
+                elif kind == "timeout":
+                    self._on_abort(rid, keep_prompt=False)
+                elif kind == "requeue":
+                    self._on_abort(rid, keep_prompt=True)
+                elif kind in ("shed", "reject"):
+                    self._prompts.pop(rid, None)
         return len(events)
 
     def run_to_completion(self, max_steps: int = 100_000) -> dict:
@@ -165,7 +171,8 @@ class ServingEngine:
         logits, self.cache = bundle.fn(
             self.server.params, self.cache, jnp.asarray(self._pos),
             jnp.asarray(self._tok))
-        toks = np.asarray(jnp.argmax(logits[:, -1:, :], axis=-1))[:, 0]
+        with tel.span("serve.decode_sync", rows=len(slots)):
+            toks = np.asarray(jnp.argmax(logits[:, -1:, :], axis=-1))[:, 0]
         for slot, rid in slots.items():
             self._outputs[rid].append(int(toks[slot]))
             self._pos[slot] += 1
@@ -177,36 +184,45 @@ class ServingEngine:
         slot = self.batcher.slot_of(rid)
         prompt = self._prompts[rid]
         S_p = prompt.shape[0]
-        logits, pcache = self._prefill_fn(self.server.params, prompt[None, :])
-        kv = {n: np.asarray(pcache[n][:, 0]) for n in ("k", "v")}
-        if self.mode == "disagg" and not self._degraded:
-            geom = tuple(sorted((n, tuple(a.shape)) for n, a in kv.items()))
-            if geom not in self._ship_plans:
-                self._ship_plans[geom] = plan_kv_ship(kv, self.path)
-            try:
-                kv, res = ship_kv(kv, self._ship_plans[geom], rid,
-                                  step=self.batcher.now(), route=self.route,
-                                  retry=self.retry,
-                                  max_reships=self.max_reships,
-                                  topo=self.topo, log=self.log,
-                                  timeout_s=self.ship_timeout_s)
-                self.batcher.note_ship(rid, reships=res.reships,
-                                       reroutes=res.reroutes)
-            except ShipError as e:
-                # no surviving route: hand the KV over in memory from here
-                # on (collocated mono fallback) and flag it
-                self._degraded = True
-                self.batcher.degrade(reason=str(e))
-        cache = dict(self.cache)
-        for n, leaf in kv.items():
-            cache[n] = self.cache[n].at[:, slot, :S_p].set(
-                jnp.asarray(leaf).astype(self.cache[n].dtype))
-        self.cache = cache
-        first = int(np.asarray(jnp.argmax(logits[0, -1])))
-        self._pos[slot] = S_p
-        self._tok[slot, 0] = first
-        self._outputs[rid] = [first]
-        self._decoding[slot] = rid
+        with tel.span("serve.admit", rid=rid, tokens=S_p):
+            with tel.span("serve.prefill", tokens=S_p):
+                logits, pcache = self._prefill_fn(self.server.params,
+                                                  prompt[None, :])
+            with tel.span("serve.kv_to_host",
+                          bytes=pcache["k"].nbytes + pcache["v"].nbytes):
+                kv = {n: np.asarray(pcache[n][:, 0]) for n in ("k", "v")}
+            if self.mode == "disagg" and not self._degraded:
+                geom = tuple(sorted((n, tuple(a.shape))
+                                    for n, a in kv.items()))
+                if geom not in self._ship_plans:
+                    self._ship_plans[geom] = plan_kv_ship(kv, self.path)
+                try:
+                    kv, res = ship_kv(kv, self._ship_plans[geom], rid,
+                                      step=self.batcher.now(),
+                                      route=self.route, retry=self.retry,
+                                      max_reships=self.max_reships,
+                                      topo=self.topo, log=self.log,
+                                      timeout_s=self.ship_timeout_s)
+                    self.batcher.note_ship(rid, reships=res.reships,
+                                           reroutes=res.reroutes)
+                except ShipError as e:
+                    # no surviving route: hand the KV over in memory from
+                    # here on (collocated mono fallback) and flag it
+                    self._degraded = True
+                    self.batcher.degrade(reason=str(e))
+            with tel.span("serve.cache_insert",
+                          bytes=sum(a.nbytes for a in kv.values())):
+                cache = dict(self.cache)
+                for n, leaf in kv.items():
+                    cache[n] = self.cache[n].at[:, slot, :S_p].set(
+                        jnp.asarray(leaf).astype(self.cache[n].dtype))
+                self.cache = cache
+            with tel.span("serve.first_token"):
+                first = int(np.asarray(jnp.argmax(logits[0, -1])))
+            self._pos[slot] = S_p
+            self._tok[slot, 0] = first
+            self._outputs[rid] = [first]
+            self._decoding[slot] = rid
 
     def _on_complete(self, rid: int) -> None:
         slot = None

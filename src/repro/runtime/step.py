@@ -705,7 +705,8 @@ def build_serve_step(rc: RunConfig, mesh, kind: Optional[str] = None) -> StepBun
                                   dp=dp),
             cache_defs, is_leaf=is_pd_leaf)
 
-        def body(params, cache, pos, tokens):
+        # named, so its program runs as `jit_serve_decode`
+        def serve_decode(params, cache, pos, tokens):
             p = gather_top(params)
             if getattr(pos, "ndim", 0) >= 1 and batch_shardable and manual:
                 # per-sequence positions arrive replicated (full (B,));
@@ -729,9 +730,11 @@ def build_serve_step(rc: RunConfig, mesh, kind: Optional[str] = None) -> StepBun
                             jax.tree.map(lambda s: _manual_part(s, manual),
                                          cache_specs,
                                          is_leaf=lambda x: isinstance(x, P)))
-        stepped = jax.shard_map(body, mesh=mesh, in_specs=in_specs_manual,
+        stepped = jax.shard_map(serve_decode, mesh=mesh,
+                                in_specs=in_specs_manual,
                                 out_specs=out_specs_manual,
-                                axis_names=manual, check_vma=False) if manual else body
+                                axis_names=manual,
+                                check_vma=False) if manual else serve_decode
         shard = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                                        is_leaf=lambda x: isinstance(x, P))
         fn = jax.jit(stepped,
