@@ -148,15 +148,15 @@ class HybridLM:
             ssm_out.append(ssm_n)
             conv_out.append(conv_n)
             h = L.rms_norm(x, sp["ln1"], c.norm_eps)
-            a, kc, vc = L.decode_attention(
+            a, k_row, v_row = L.decode_attention(
                 sp["attn"], h, self.dims,
                 k_cache=cache["shared_k"][site], v_cache=cache["shared_v"][site],
                 pos=pos, ring=False)
             x = x + a
             h = L.rms_norm(x, sp["ln2"], c.norm_eps)
             x = x + L.swiglu(sp["ffn"], h)
-            k_out.append(kc)
-            v_out.append(vc)
+            k_out.append(k_row)
+            v_out.append(v_row)
         # trailing mamba layers (if num_layers % attn_every)
         rem = c.num_layers - n_sites * per
         if rem:
@@ -172,8 +172,8 @@ class HybridLM:
         new_cache = {
             "ssm": jnp.concatenate(ssm_out, axis=0),
             "conv": jnp.concatenate(conv_out, axis=0),
-            "shared_k": jnp.stack(k_out, axis=0),
-            "shared_v": jnp.stack(v_out, axis=0),
+            "shared_k": L.put_kv_rows(cache["shared_k"], jnp.stack(k_out), pos),
+            "shared_v": L.put_kv_rows(cache["shared_v"], jnp.stack(v_out), pos),
         }
         return logits, new_cache
 

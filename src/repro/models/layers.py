@@ -160,19 +160,34 @@ def attention(p: dict, x: jax.Array, dims: AttnDims, *,
     return constrain(out, None, None, None)
 
 
+def cache_slot(pos: jax.Array, W: int, ring: bool) -> jax.Array:
+    """The cache slot a token at absolute position `pos` is written to: its
+    ring slot under a sliding window, else its position (the last slot
+    once the cache is full)."""
+    return jnp.mod(pos, W) if ring else jnp.minimum(pos, W - 1)
+
+
 def decode_attention(p: dict, x: jax.Array, dims: AttnDims, *,
                      k_cache: jax.Array, v_cache: jax.Array,
                      pos: jax.Array,
                      ring: bool = False) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token attention against a cache.
+    """One-token attention against a cache that it only reads.
 
-    x: (B, 1, d); k_cache/v_cache: (B, W, KH, Dh).  `pos` is the number of
-    tokens already in the cache (the new token's absolute position) — a
-    scalar when every row sits at the same depth, or a (B,) vector when the
-    serving tier's continuous batcher has each slot at its own depth.  When
-    `ring` (sliding window), the cache is a ring buffer of width W and keys
-    were rope'd at insertion; otherwise W == max_len and slot i == position i.
-    Returns (attn_out (B,1,n_q), new_k_cache, new_v_cache).
+    x: (B, 1, d); k_cache/v_cache: (B, W, KH, Dh), the tokens before this
+    one.  `pos` is the number of tokens already in the cache (the new
+    token's absolute position) — a scalar when every row sits at the same
+    depth, or a (B,) vector when the serving tier's continuous batcher has
+    each slot at its own depth.  When `ring` (sliding window), the cache is
+    a ring buffer of width W and keys were rope'd at insertion; otherwise
+    W == max_len and slot i == position i.
+
+    The new token's key and value are not written here: the token is
+    scored against the cache with its own slot (`cache_slot`) masked, and
+    its own score and value term join the softmax — the same sums as
+    attending after writing the row.  The caller writes the returned rows
+    after every read of its cache (`put_kv_rows`), so the write is in place.
+    Returns (attn_out (B,1,n_q), k_row, v_row); rows (B, KH, Dh) in the
+    cache's dtype.
     """
     B, _, _ = x.shape
     H, KH, Dh = dims.num_heads, dims.num_kv_heads, dims.head_dim
@@ -194,40 +209,50 @@ def decode_attention(p: dict, x: jax.Array, dims: AttnDims, *,
             ppos = jnp.full((1,), pos, jnp.int32)
         q = apply_rope(q, ppos, dims.rope_theta)
         k = apply_rope(k, ppos, dims.rope_theta)
-    if vec:
-        slot_v = jnp.mod(pos, W) if ring else jnp.minimum(pos, W - 1)
-        rows = jnp.arange(B)
-        k_cache = k_cache.at[rows, slot_v].set(k[:, 0].astype(k_cache.dtype))
-        v_cache = v_cache.at[rows, slot_v].set(v[:, 0].astype(v_cache.dtype))
-    else:
-        slot = jnp.where(ring, pos % W, jnp.minimum(pos, W - 1)) if ring else pos
-        k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), (0, slot, 0, 0))
+    k_row = k[:, 0].astype(k_cache.dtype)
+    v_row = v[:, 0].astype(v_cache.dtype)
 
     qf = (q.astype(jnp.float32) * Dh ** -0.5).reshape(B, 1, KH, g, Dh)
-    kf = k_cache.astype(jnp.float32)
-    s = jnp.einsum("bqkgd,bskd->bkgqs", qf, kf)          # (B,KH,g,1,W)
+    s = jnp.einsum("bqkgd,bskd->bkgqs", qf, k_cache.astype(jnp.float32))  # (B,KH,g,1,W)
     s = constrain(s, None, None, None, None, TP_AXIS)
+    s_own = jnp.einsum("bqkgd,bkd->bkgq", qf, k_row.astype(jnp.float32))[..., None]
     idx = jnp.arange(W)
-    if vec:
-        pb = pos[:, None]                                # (B, 1)
-        if ring:
-            valid = (pb - jnp.mod(pb - idx[None, :], W)) >= 0
-        else:
-            valid = idx[None, :] <= pb                   # (B, W)
-        s = jnp.where(valid[:, None, None, None, :], s, -jnp.inf)
+    pb = pos[:, None] if vec else jnp.reshape(pos, (1, 1))      # (B|1, 1)
+    if ring:
+        # slot j holds absolute position pos - ((pos - j) mod W); valid iff >= 0
+        valid = (pb - jnp.mod(pb - idx[None, :], W)) >= 0
     else:
-        if ring:
-            # slot j holds absolute position pos - ((pos - j) mod W); valid iff >= 0
-            absp = pos - jnp.mod(pos - idx, W)
-            valid = absp >= 0
-        else:
-            valid = idx <= pos
-        s = jnp.where(valid[None, None, None, None, :], s, -jnp.inf)
-    p_attn = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgqs,bskd->bqkgd", p_attn, v_cache.astype(jnp.float32))
+        valid = idx[None, :] <= pb                                  # (B|1, W)
+    valid = valid & (idx[None, :] != cache_slot(pb, W, ring))      # own slot: s_own
+    s = jnp.where(valid[:, None, None, None, :], s, -jnp.inf)
+    p_attn = jax.nn.softmax(jnp.concatenate([s, s_own], axis=-1), axis=-1)
+    o = jnp.einsum("bkgqs,bskd->bqkgd", p_attn[..., :W], v_cache.astype(jnp.float32))
+    o = o + jnp.einsum("bkgq,bkd->bqkgd", p_attn[..., W], v_row.astype(jnp.float32))
     o = o.reshape(B, 1, H * Dh).astype(x.dtype)
-    return o @ p["wo"], k_cache, v_cache
+    return o @ p["wo"], k_row, v_row
+
+
+def put_kv_rows(cache: jax.Array, rows: jax.Array, pos: jax.Array,
+                ring: bool = False) -> jax.Array:
+    """Write one new token's rows of every layer into a stacked cache.
+
+    cache: (L, B, W, KH, Dh); rows: (L, B, KH, Dh), row b going to slot
+    `cache_slot(pos[b])` (one slot for all when `pos` is a scalar).  One
+    `dynamic_update_slice` per batch row (one in all for a scalar `pos`),
+    in place on a donated cache.  Call it outside the layer loop: there a
+    row write, which arrives head_dim-minor, makes the TPU compiler relay
+    the whole carried stack out where the device keeps the slots minor
+    (head_dim 64).
+    """
+    W = cache.shape[2]
+    rows = rows.astype(cache.dtype)[:, :, None]                  # (L,B,1,KH,Dh)
+    slot = cache_slot(pos, W, ring)
+    if getattr(pos, "ndim", 0) == 0:
+        return jax.lax.dynamic_update_slice(cache, rows, (0, 0, slot, 0, 0))
+    for b in range(cache.shape[1]):
+        cache = jax.lax.dynamic_update_slice(cache, rows[:, b:b + 1],
+                                             (0, b, slot[b], 0, 0))
+    return cache
 
 
 def swiglu(p: dict, x: jax.Array) -> jax.Array:

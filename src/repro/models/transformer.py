@@ -309,12 +309,16 @@ class Transformer:
         ring = c.sliding_window is not None
         has_cross = bool(c.encoder_layers)
 
+        # the caches are read-only inside the layer loop: each layer
+        # yields its new K/V row, and the rows of all layers are written
+        # into the donated caches after it, in place (put_kv_rows)
         def step(x, inp):
             lp, kc, vc, xk, xv = inp
             lp = gather(lp)
             h = L.rms_norm(x, lp["ln1"], c.norm_eps)
-            a, kc, vc = L.decode_attention(lp["attn"], h, self.dims,
-                                           k_cache=kc, v_cache=vc, pos=pos, ring=ring)
+            a, k_row, v_row = L.decode_attention(lp["attn"], h, self.dims,
+                                                 k_cache=kc, v_cache=vc,
+                                                 pos=pos, ring=ring)
             x = x + a
             if has_cross:
                 h = L.rms_norm(x, lp["lnx"], c.norm_eps)
@@ -325,16 +329,16 @@ class Transformer:
                 x = x + y
             else:
                 x = x + L.swiglu(lp["ffn"], h)
-            return x, (kc, vc)
+            return x, (k_row, v_row)
 
-        xk = cache.get("xk", cache["k"])   # placeholder when no cross-attn
-        xv = cache.get("xv", cache["v"])
-        x, (k_new, v_new) = jax.lax.scan(
-            step, x, (params["blocks"], cache["k"], cache["v"], xk, xv))
+        x, (k_rows, v_rows) = jax.lax.scan(
+            step, x, (params["blocks"], cache["k"], cache["v"],
+                      cache.get("xk"), cache.get("xv")))
         x = L.rms_norm(x, params["ln_f"], c.norm_eps)
         logits = (x @ self._head(params)).astype(jnp.float32)
         logits = constrain(logits, None, None, TP_AXIS)
-        new_cache = dict(cache, k=k_new, v=v_new)
+        new_cache = dict(cache, k=L.put_kv_rows(cache["k"], k_rows, pos, ring),
+                         v=L.put_kv_rows(cache["v"], v_rows, pos, ring))
         return logits, new_cache
 
     def _cross_decode(self, p: dict, x: jax.Array, xk: jax.Array, xv: jax.Array) -> jax.Array:

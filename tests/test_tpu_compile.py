@@ -13,13 +13,21 @@ this file.  Keep these tests in this one file.
 """
 from __future__ import annotations
 
+import dataclasses
+import math
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from repro.configs import CommConfig, RunConfig, ShapeConfig, TrainConfig, get_config
 from repro.core.compress import QBLOCK
 from repro.kernels import ops
+from repro.models.param import is_pd_leaf
+from repro.runtime.step import build_serve_step
 
 
 @pytest.fixture(scope="module")
@@ -108,3 +116,59 @@ def test_int8_codec_compiles(one_chip, case):
         return ops.dequant_int8(q, s, block=block, impl="pallas")
 
     _assert_kernel(roundtrip, x, kernels=2)
+
+
+# the serving cells' decode steps: (arch, slots, cache length), depth cut
+DECODE_CELLS = {
+    "mha_hd64": ("qwen1.5-0.5b", 32, 2048),     # 16 heads of 64
+    "gqa_hd128": ("qwen2.5-14b", 32, 1024),     # 40 query / 8 KV heads of 128
+}
+
+
+def _top_level_copy_bytes(hlo: str) -> list[int]:
+    """Bytes of every copy/copy-start result outside fused computations:
+    the copies that write a buffer of their own."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", hlo))
+    sizes, comp = [], None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            comp = head.group(1)
+            continue
+        m = re.search(r"= \(?(\w+)\[([\d,]*)\]\S*\s.*?\b(copy|copy-start)\(", line)
+        if comp is not None and comp not in fused and m:
+            n = math.prod(int(d) for d in m.group(2).split(",") if d)
+            bits = re.search(r"\d+", m.group(1))      # bf16, f32, s8; pred
+            sizes.append(n * (int(bits.group()) // 8 if bits else 1))
+    return sizes
+
+
+@pytest.mark.parametrize("case", list(DECODE_CELLS))
+def test_serve_decode_updates_cache_in_place(one_chip, case):
+    """The decode step writes the new rows into the donated cache and
+    copies no layer's worth of it, at the serving cells' shapes."""
+    arch, slots, cache_len = DECODE_CELLS[case]
+    cfg = dataclasses.replace(get_config(arch), num_layers=2)
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    rc = RunConfig(model=cfg, shape=ShapeConfig("serve", cache_len, slots, "decode"),
+                   comm=CommConfig(), train=TrainConfig())
+    bundle = build_serve_step(rc, mesh, kind="decode")
+
+    def spec(specs, defs):
+        return jax.tree.map(
+            lambda pd, s: jax.ShapeDtypeStruct(pd.shape, jnp.dtype(pd.dtype),
+                                               sharding=NamedSharding(mesh, s)),
+            defs, specs, is_leaf=is_pd_leaf)
+
+    rep = NamedSharding(mesh, PartitionSpec())
+    compiled = bundle.fn.lower(
+        spec(bundle.state_specs["params"], bundle.param_defs),
+        spec(bundle.state_specs["cache"], bundle.cache_defs),
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=rep)).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_serve_decode")
+    layer_k = slots * cache_len * cfg.num_kv_heads * cfg.resolved_head_dim * 2
+    assert max(_top_level_copy_bytes(hlo), default=0) < layer_k
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * layer_k
