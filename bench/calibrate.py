@@ -46,7 +46,7 @@ def sweep(cell, dm, args):
         win = drive_serve.window(cell, dm, mesh, eng, probe, counter, mix)
         eng.run_to_completion()
         emit(args.out, {"mode": "sweep", "rate_per_s": rate,
-                        **win["metrics"], "ttft_p50_ms": win["ttft_p50_ms"],
+                        **win["metrics"], **win["notes"],
                         "attempted": win["attempted"],
                         "failed": win["failed"], "backlog": win["backlog"],
                         "window_s": win["window_s"],

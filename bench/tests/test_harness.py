@@ -30,10 +30,10 @@ def test_new_files_found_by_name(tmp_path):
                               "traffic": "newmix", "chips": 1, "why": "t"})
     spec["per_layer"].append({"name": "new_metric", "unit": "ms",
                               "better": "lower", "source": "host_clock",
-                              "layer": "device", "moves": "itl_p95_ms",
+                              "layer": "device", "moves": "itl_p99_ms",
                               "workloads": ["newmix.other"]})
     for m in spec["end_to_end"]:
-        if m["name"] == "itl_p95_ms":
+        if m["name"] == "itl_p99_ms":
             m["workloads"].append("newmix.other")
     json.dump(spec, open(os.path.join(root, "BENCHMARK.json"), "w"))
     json.dump({**tiny.CONFIG, "name": "other", "hidden_size": 256},
@@ -45,7 +45,7 @@ def test_new_files_found_by_name(tmp_path):
     cell = harness.load_cell(root, "newmix.other")
     assert cell.config["hidden_size"] == 256
     assert cell.mix["arrivals"]["rate_per_s"] == 3.0
-    assert harness.end_to_end_names(cell) == ["itl_p95_ms", "setup_s"]
+    assert harness.end_to_end_names(cell) == ["itl_p99_ms", "setup_s"]
     names = [m["name"] for m in harness.per_layer_entries(cell)]
     assert names == ["new_metric"]
     assert harness.reader(root, "new_metric")(None) == 7.0
